@@ -102,6 +102,9 @@ def _child(iters: int) -> None:
 # ------------------------------------------------------------------ driver
 def run(iters: int = 20) -> List[Tuple[str, float, str]]:
     env = dict(os.environ)
+    # The child simulates its devices on the host CPU; it must never try to
+    # open a chip this parent process may hold.
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     env["XLA_FLAGS"] = (

@@ -37,6 +37,8 @@ import logging
 import multiprocessing
 import os
 import pickle
+import sys
+import threading
 import weakref
 from abc import ABC, abstractmethod
 from dataclasses import dataclass
@@ -264,6 +266,53 @@ class _Proxy:
         return f"_Proxy({object.__getattribute__(self, '_cell')!r})"
 
 
+# ---------------------------------------------------------------------------
+# Child processes are CPU actors
+# ---------------------------------------------------------------------------
+# A chip belongs to one process at a time.  The parent owns it; process-
+# backed actors (rollout workers, remote hosts) run their JAX on the host
+# CPU by design, so a child must never open the device its parent holds.
+_ENV_LOCK = threading.Lock()
+
+
+def _parent_on_cpu() -> bool:
+    """True when this process's JAX is pinned to the CPU backend."""
+    jax = sys.modules.get("jax")
+    platforms = jax.config.jax_platforms if jax is not None else os.environ.get("JAX_PLATFORMS")
+    return (platforms or "").strip().lower() == "cpu"
+
+
+def child_start_method(requested: Optional[str] = None) -> str:
+    """Start method for an actor child process.
+
+    A parent pinned to the CPU may fork (~10ms per worker vs ~1s for spawn,
+    and the chaos suites restart workers constantly); the child only
+    unpickles its factory and serves calls.  Any other parent may hold an
+    accelerator, whose client a forked child would inherit, so its children
+    are always spawned.
+    """
+    if not _parent_on_cpu():
+        return "spawn"
+    if requested is not None:
+        return requested
+    return "fork" if "fork" in multiprocessing.get_all_start_methods() else "spawn"
+
+
+def start_off_chip(proc: Any) -> None:
+    """Start ``proc`` with ``JAX_PLATFORMS=cpu`` in its environment, so a
+    spawned child's JAX takes the CPU backend before anything imports it."""
+    with _ENV_LOCK:
+        saved = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            proc.start()
+        finally:
+            if saved is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = saved
+
+
 class _ReturnTarget:
     """Picklable factory wrapper for a pre-built (picklable) target object."""
 
@@ -295,16 +344,7 @@ class ProcessCell(Cell):
         self._prefix_base = f"rfl{os.getpid()}x{next(_cell_seq)}"
         self._generation = 0
         self._decoder: Any = None
-        if start_method is None:
-            # Default to fork where available: ~10ms per worker vs ~1s for
-            # forkserver/spawn (measured; the chaos suites restart workers
-            # constantly).  Fork-with-threads is a known CPython hazard, but
-            # the child here only unpickles the factory and serves numpy
-            # calls — it never touches the driver's JAX/logging state.  Pass
-            # ``ProcessBackend(start_method="forkserver"|"spawn")`` for
-            # drivers where that tradeoff goes the other way.
-            start_method = "fork" if "fork" in multiprocessing.get_all_start_methods() else "spawn"
-        self._ctx = multiprocessing.get_context(start_method)
+        self._ctx = multiprocessing.get_context(child_start_method(start_method))
         self._proc: Any = None
         self._conn: Any = None
         self._proxy = _Proxy(self)
@@ -339,7 +379,7 @@ class ProcessCell(Cell):
             daemon=True,
             name="actor-cell",
         )
-        self._proc.start()
+        start_off_chip(self._proc)
         child.close()
 
     # ------------------------------------------------------------------ rpc

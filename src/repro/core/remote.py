@@ -54,6 +54,7 @@ from repro.core.executor import (
     ExecutionBackend,
     _Proxy,
     _ReturnTarget,
+    start_off_chip,
 )
 from repro.core.transport import (
     FrameDecoder,
@@ -241,21 +242,22 @@ class LocalHostHandle:
         return f"LocalHostHandle({self.address!r}, alive={self.alive})"
 
 
-def start_local_host(port: int = 0, start_method: str = "spawn") -> LocalHostHandle:
+def start_local_host(port: int = 0) -> LocalHostHandle:
     """Launch a RemoteHost on localhost; returns once its port is bound.
 
-    Spawn (not fork) so the host interpreter is clean — same reasoning as
-    JAX workers on the process backend: the host will likely build jitted
-    targets, and fork would inherit the driver's XLA threads.
+    Spawned (not forked) so the host interpreter is clean: the host will
+    likely build jitted targets, and fork would inherit the parent's XLA
+    threads.  Like every actor child it is a CPU process
+    (``start_off_chip``): the parent keeps the chip.
     """
     import multiprocessing
 
-    ctx = multiprocessing.get_context(start_method)
+    ctx = multiprocessing.get_context("spawn")
     parent, child = ctx.Pipe()
     proc = ctx.Process(
         target=_host_main, args=(port, child), daemon=True, name="remote-host"
     )
-    proc.start()
+    start_off_chip(proc)
     child.close()
     if not parent.poll(30.0):
         proc.terminate()

@@ -6,8 +6,8 @@ references materialize the ``next_values``/``deltas`` intermediates in HBM
 and dispatch one tiny elementwise op per time step; these kernels instead
 grid over batch blocks and keep the whole [T, block_b] column panel resident
 in VMEM: the delta computation, the reverse recurrence, and the value-target
-epilogue fuse into a single pass, so HBM traffic is exactly the four input
-streams plus the two outputs.
+epilogue fuse into one reverse loop over rows (ref-indexed row loads and
+stores), so HBM traffic is exactly the input streams plus the two outputs.
 
 Layout: all inputs are time-major [T, B] (the same layout the scan
 references take), ``last_value`` is [B].  The wrappers flatten arbitrary
@@ -37,60 +37,55 @@ __all__ = ["gae_pallas", "vtrace_pallas"]
 _BLOCK_B = 128  # lane dimension of one batch panel
 
 
-def _reverse_scan(deltas: jax.Array, decay: jax.Array, T: int) -> jax.Array:
-    """acc_t = delta_t + decay_t * acc_{t+1}, returned as the full [T, Bb]
-    array.  Runs as a ``fori_loop`` over VMEM-resident panels (the rwkv6
-    kernel idiom: dynamic row slices against register/VMEM arrays)."""
-
-    def step(i, carry_out):
-        carry, out = carry_out
-        t = T - 1 - i
-        d_t = jax.lax.dynamic_slice_in_dim(deltas, t, 1, 0)[0]
-        k_t = jax.lax.dynamic_slice_in_dim(decay, t, 1, 0)[0]
-        acc = d_t + k_t * carry
-        out = jax.lax.dynamic_update_slice(out, acc[None], (t, 0))
-        return acc, out
-
-    carry0 = jnp.zeros(deltas.shape[1:], deltas.dtype)
-    _, out = jax.lax.fori_loop(0, T, step, (carry0, jnp.zeros_like(deltas)))
-    return out
+def _row(ref, t):
+    """Row ``t`` of a [T, Bb] VMEM panel as [1, Bb] float32 (a dynamic
+    sublane load; value-level dynamic slices do not lower on TPU)."""
+    return ref[pl.ds(t, 1), :].astype(jnp.float32)
 
 
 def _gae_kernel(r_ref, v_ref, d_ref, last_ref, adv_ref, ret_ref, *, gamma, lam, T):
-    r = r_ref[...].astype(jnp.float32)  # [T, Bb]
-    v = v_ref[...].astype(jnp.float32)
-    nd = 1.0 - d_ref[...].astype(jnp.float32)
-    last = last_ref[...].astype(jnp.float32)  # [1, Bb]
+    # Reverse loop over rows: the bootstrap boundary (V(s_T) = last) enters
+    # as the initial next-value carry, never by padding T.
+    def step(i, carry):
+        acc, nv = carry
+        t = T - 1 - i
+        v = _row(v_ref, t)
+        nd = 1.0 - _row(d_ref, t)
+        delta = _row(r_ref, t) + gamma * nd * nv - v
+        acc = delta + gamma * lam * nd * acc
+        adv_ref[pl.ds(t, 1), :] = acc.astype(adv_ref.dtype)
+        ret_ref[pl.ds(t, 1), :] = (acc + v).astype(ret_ref.dtype)
+        return acc, v
 
-    nv = jnp.concatenate([v[1:], last], axis=0)  # bootstrap boundary in-kernel
-    deltas = r + gamma * nd * nv - v
-    adv = _reverse_scan(deltas, gamma * lam * nd, T)
-    adv_ref[...] = adv.astype(adv_ref.dtype)
-    ret_ref[...] = (adv + v).astype(ret_ref.dtype)
+    last = last_ref[...].astype(jnp.float32)  # [1, Bb]
+    jax.lax.fori_loop(0, T, step, (jnp.zeros_like(last), last))
 
 
 def _vtrace_kernel(
     blp_ref, tlp_ref, r_ref, v_ref, d_ref, last_ref, vs_ref, pg_ref,
     *, gamma, rho_clip, c_clip, T,
 ):
-    blp = blp_ref[...].astype(jnp.float32)
-    tlp = tlp_ref[...].astype(jnp.float32)
-    r = r_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
-    d = d_ref[...].astype(jnp.float32)
-    last = last_ref[...].astype(jnp.float32)  # [1, Bb]
+    # Carries: acc = vs_t - v_t, the next value V(s_{t+1}) and the next
+    # target vs_{t+1}; both start at the bootstrap value.
+    def step(i, carry):
+        acc, nv, nvs = carry
+        t = T - 1 - i
+        v = _row(v_ref, t)
+        r = _row(r_ref, t)
+        rhos = jnp.exp(_row(tlp_ref, t) - _row(blp_ref, t))
+        clipped_rhos = jnp.minimum(rho_clip, rhos)
+        cs = jnp.minimum(c_clip, rhos)
+        discount = gamma * (1.0 - _row(d_ref, t))
+        delta = clipped_rhos * (r + discount * nv - v)
+        acc = delta + discount * cs * acc
+        vs = acc + v
+        vs_ref[pl.ds(t, 1), :] = vs.astype(vs_ref.dtype)
+        pg = clipped_rhos * (r + discount * nvs - v)
+        pg_ref[pl.ds(t, 1), :] = pg.astype(pg_ref.dtype)
+        return acc, v, vs
 
-    rhos = jnp.exp(tlp - blp)
-    clipped_rhos = jnp.minimum(rho_clip, rhos)
-    cs = jnp.minimum(c_clip, rhos)
-    discounts = gamma * (1.0 - d)
-    nv = jnp.concatenate([v[1:], last], axis=0)
-    deltas = clipped_rhos * (r + discounts * nv - v)
-    vs = _reverse_scan(deltas, discounts * cs, T) + v
-    next_vs = jnp.concatenate([vs[1:], last], axis=0)
-    pg_adv = clipped_rhos * (r + discounts * next_vs - v)
-    vs_ref[...] = vs.astype(vs_ref.dtype)
-    pg_ref[...] = pg_adv.astype(pg_ref.dtype)
+    last = last_ref[...].astype(jnp.float32)  # [1, Bb]
+    jax.lax.fori_loop(0, T, step, (jnp.zeros_like(last), last, last))
 
 
 def _flatten_tm(x: jax.Array) -> jax.Array:

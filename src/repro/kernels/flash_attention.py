@@ -7,8 +7,16 @@ accumulator in VMEM scratch.  BlockSpecs stream one (block_q x D) Q tile and
 (multiples of 128 for the matmul dims).  GQA is expressed in the K/V index
 maps (query head h reads kv head h // group).
 
-Validated against kernels/ref.py oracles in interpret mode (CPU container);
-on TPU the same code runs compiled.
+``pallas_call`` has no autodiff rule, and the learner differentiates
+through attention, so the op is a ``jax.custom_vjp``: the forward is the
+Pallas kernel and the backward recomputes attention with the jnp oracle
+``ref.chunked_attention`` (per-chunk rematerialized) and takes its VJP.
+Gradients therefore cost one extra attention forward in XLA; no Pallas
+backward kernel exists yet.
+
+Validated against kernels/ref.py oracles in interpret mode on CPU (forward
+and gradients, ``tests/test_kernels.py``); compiled for a v5e chip in
+``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
@@ -21,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ref import chunked_attention
 
 __all__ = ["flash_attention_pallas"]
 
@@ -95,22 +105,10 @@ def _flash_kernel(
         o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
 
 
-def flash_attention_pallas(
-    q: jax.Array,  # [B, Sq, H, D]
-    k: jax.Array,  # [B, Sk, KV, D]
-    v: jax.Array,
-    causal: bool = True,
-    window: int = 0,
-    q_offset: int = 0,
-    block_q: int = 128,
-    block_k: int = 128,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
+def _flash_forward(q, k, v, causal, window, q_offset, block_q, block_k, interpret):
     B, Sq, H, D = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     g = H // KV
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     assert Sq % block_q == 0 and Sk % block_k == 0, "pad seq to block multiples"
@@ -149,3 +147,47 @@ def flash_attention_pallas(
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, window, q_offset, block_q, block_k, interpret):
+    return _flash_forward(q, k, v, causal, window, q_offset, block_q, block_k, interpret)
+
+
+def _flash_fwd(q, k, v, causal, window, q_offset, block_q, block_k, interpret):
+    out = _flash_forward(q, k, v, causal, window, q_offset, block_q, block_k, interpret)
+    return out, (q, k, v)
+
+
+def _flash_bwd(causal, window, q_offset, block_q, block_k, interpret, res, g):
+    q, k, v = res
+    _, vjp = jax.vjp(
+        lambda q, k, v: chunked_attention(
+            q, k, v, causal=causal, window=window, q_offset=q_offset
+        ),
+        q, k, v,
+    )
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_attention_pallas(
+    q: jax.Array,  # [B, Sq, H, D]
+    k: jax.Array,  # [B, Sk, KV, D]
+    v: jax.Array,
+    causal: bool = True,
+    window: int = 0,
+    q_offset: int = 0,
+    block_q: int = 128,
+    block_k: int = 128,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Differentiable flash attention; backward = VJP of the jnp oracle."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _flash(
+        q, k, v, bool(causal), int(window), int(q_offset),
+        int(block_q), int(block_k), bool(interpret),
+    )

@@ -130,10 +130,7 @@ def run_one(
 
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        # jax <= 0.4.x returns a one-element list of dicts; newer returns a dict.
         raw_cost = compiled.cost_analysis() or {}
-        if isinstance(raw_cost, (list, tuple)):
-            raw_cost = raw_cost[0] if raw_cost else {}
         # Trip-count-aware per-device analysis (raw cost_analysis counts
         # while bodies once; our models are scans over blocks).
         walker = analyze_hlo(compiled.as_text())

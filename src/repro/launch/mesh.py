@@ -12,12 +12,9 @@ __all__ = ["make_production_mesh", "make_local_mesh"]
 
 
 def _mk(shape, axes):
-    # jax.sharding.AxisType landed in jax 0.5.x; older releases neither have
-    # the enum nor accept an ``axis_types`` kwarg to ``jax.make_mesh``.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -28,5 +25,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_local_mesh():
-    """Single-device mesh (CPU smoke tests)."""
-    return _mk((1, 1), ("data", "model"))
+    """``(n, 1)`` data x model mesh over the n devices this host has: one
+    device for CPU smoke tests, a v5e host's 4 chips data-parallel."""
+    return _mk((jax.device_count(), 1), ("data", "model"))

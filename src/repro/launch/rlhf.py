@@ -35,17 +35,23 @@ def make_rlhf_worker(
     horizon: int = 16,
     d_model: int = 32,
     n_layers: int = 2,
+    num_heads: int = 2,
+    num_kv_heads: int = 0,
     decode: str = "cache",
     seed: int = 0,
     lr: float = 3e-3,
 ):
-    """One vectorized LM rollout worker over TokenEnv (shared with tests)."""
+    """One vectorized LM rollout worker over TokenEnv (shared with tests).
+
+    ``num_kv_heads=0`` means multi-head attention (as many KV heads as
+    query heads)."""
     from repro.optim import adam
     from repro.rl import LMTokenPolicy, TokenEnv, VectorizedRolloutWorker
 
     env = TokenEnv(vocab_size=vocab_size, ctx=ctx, horizon=horizon)
     policy = LMTokenPolicy(
-        ctx=ctx, vocab_size=vocab_size, d_model=d_model, n_layers=n_layers
+        ctx=ctx, vocab_size=vocab_size, d_model=d_model, n_layers=n_layers,
+        num_heads=num_heads, num_kv_heads=num_kv_heads,
     )
     return VectorizedRolloutWorker(
         env, policy, algo="ppo", num_envs=num_envs, rollout_len=rollout_len,
@@ -54,7 +60,38 @@ def make_rlhf_worker(
     )
 
 
+def decode_parity_gap(worker) -> float:
+    """Max |decode logits - forward logits| on the worker's live lanes.
+
+    Prefills a cache holding tokens 0..L-2 of each lane (the newest token
+    dropped, t forced to 0), then compares one true ``decode_step`` against
+    the no-cache forward over the full window.
+
+    Both paths run with float32 matmuls at full precision: on a TPU the
+    default f32 matmul (XLA and Pallas alike) is one bfloat16 pass, whose
+    rounding (~1e-2 on these logits) would swamp any cache-path error."""
+    import jax
+    import numpy as np
+
+    policy = worker.policy
+    obs = np.asarray(worker.vstate.obs)
+    prev = obs.copy()
+    prev[:, policy.ctx] -= 1
+    prev[:, policy.ctx + 1] = 0
+    state = policy.init_lane_state(obs.shape[0])
+    with jax.default_matmul_precision("highest"):
+        _, _, _, state = jax.jit(policy.compute_actions_stateful)(
+            worker.params, prev,
+            jax.random.split(jax.random.PRNGKey(0), obs.shape[0]),
+            state,
+        )
+        return float(jax.jit(policy.decode_parity_gap)(worker.params, obs, state))
+
+
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--workers", type=int, default=2)
@@ -65,6 +102,8 @@ def main() -> None:
     ap.add_argument("--horizon", type=int, default=16)
     ap.add_argument("--d-model", type=int, default=32)
     ap.add_argument("--layers", type=int, default=2)
+    ap.add_argument("--heads", type=int, default=2)
+    ap.add_argument("--kv-heads", type=int, default=0, help="0 = as many as --heads")
     ap.add_argument("--train-batch", type=int, default=256)
     ap.add_argument("--sgd-iters", type=int, default=4)
     ap.add_argument("--minibatch", type=int, default=64)
@@ -89,7 +128,8 @@ def main() -> None:
         return make_rlhf_worker(
             i, num_envs=args.num_envs, rollout_len=args.rollout_len,
             vocab_size=args.vocab, ctx=args.ctx, horizon=args.horizon,
-            d_model=args.d_model, n_layers=args.layers, decode=args.decode,
+            d_model=args.d_model, n_layers=args.layers, num_heads=args.heads,
+            num_kv_heads=args.kv_heads, decode=args.decode,
             seed=args.seed, lr=args.lr,
         )
 
@@ -119,26 +159,7 @@ def main() -> None:
                 f"({tokens_per_iter / ((time.time() - t0) / (it + 1)):.0f} tok/s)"
             )
             if args.parity:
-                import jax
-                import numpy as np
-
-                lw = ws.local_worker()
-                policy = lw.policy
-                obs = np.asarray(lw.vstate.obs)
-                # Prefill a cache holding tokens 0..L-2 (drop the newest
-                # token, force t=0) so decode_parity_gap measures one true
-                # decode_step against the no-cache forward.
-                prev = obs.copy()
-                prev[:, policy.ctx] -= 1
-                prev[:, policy.ctx + 1] = 0
-                state = policy.init_lane_state(obs.shape[0])
-                _, _, _, state = policy.compute_actions_stateful(
-                    lw.params, prev,
-                    jax.random.split(jax.random.PRNGKey(0), obs.shape[0]),
-                    state,
-                )
-                gap = float(policy.decode_parity_gap(lw.params, obs, state))
-                line += f" parity_gap {gap:.2e}"
+                line += f" parity_gap {decode_parity_gap(ws.local_worker()):.2e}"
             print(line, flush=True)
     finally:
         algo.stop()

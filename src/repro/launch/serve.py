@@ -217,6 +217,9 @@ def open_loop_load(
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--policy", default="stateless",
                     choices=("stateless", "ac", "ssm"))

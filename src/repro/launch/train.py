@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Callable, List, Optional
 
 import numpy as np
 
@@ -51,7 +52,60 @@ def build_lm_flow(workers, pipes):
     return spec
 
 
+def build_trainer(cfg, batch: int, seq: int, data_shards: int, lr: float, steps: int):
+    """(workers, pipes, learner) for ``build_lm_flow`` on this host's mesh:
+    one SPMD learner over an ``(n, 1)`` data x model mesh of the n local
+    devices, fed by ``data_shards`` token-pipeline actors."""
+    from repro.configs.base import InputShape
+    from repro.core.actor import ActorPool
+    from repro.core.spmd import SPMDLearnerWorker, SPMDTrainContext
+    from repro.core.workers import WorkerSet
+    from repro.data import TokenPipeline
+    from repro.launch.mesh import make_local_mesh
+    from repro.optim import adamw, chain_clip_by_global_norm, linear_warmup_cosine
+
+    shape = InputShape("train", seq, batch, "train")
+    opt = chain_clip_by_global_norm(
+        adamw(linear_warmup_cosine(lr, 20, max(steps, 100)), weight_decay=0.1),
+        max_norm=1.0,
+    )
+    learner = SPMDLearnerWorker(SPMDTrainContext(cfg, opt, make_local_mesh()))
+    pipes = ActorPool.from_targets(
+        [
+            TokenPipeline(cfg, shape, seed=0, host_id=i, num_hosts=data_shards)
+            for i in range(data_shards)
+        ],
+        name="data",
+    )
+    return WorkerSet(learner, pipes), pipes, learner
+
+
+def train(
+    cfg,
+    steps: int,
+    batch: int,
+    seq: int,
+    data_shards: int = 2,
+    lr: float = 3e-4,
+    on_step: Optional[Callable[[int, float], None]] = None,
+):
+    """Run ``steps`` iterations of the LM flow; returns (losses, learner)."""
+    from repro.flow import Algorithm
+
+    workers, pipes, learner = build_trainer(cfg, batch, seq, data_shards, lr, steps)
+    losses: List[float] = []
+    with Algorithm.from_plan(build_lm_flow(workers, pipes), workers) as algo:
+        for step in range(steps):
+            losses.append(algo.train()["info"].get("loss", float("nan")))
+            if on_step is not None:
+                on_step(step, losses[-1])
+    return losses, learner
+
+
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b")
     ap.add_argument("--steps", type=int, default=20)
@@ -64,57 +118,33 @@ def main() -> None:
     ap.add_argument("--dot", action="store_true", help="print the flow graph and exit")
     args = ap.parse_args()
 
-    import jax
-
     from repro.checkpoint import save_pytree
     from repro.configs import get_config, reduced_config
-    from repro.configs.base import InputShape
-    from repro.core.actor import ActorPool
-    from repro.core.spmd import SPMDLearnerWorker, SPMDTrainContext
-    from repro.core.workers import WorkerSet
-    from repro.data import TokenPipeline
-    from repro.flow import Algorithm
-    from repro.launch.mesh import make_local_mesh, make_production_mesh
-    from repro.optim import adamw, chain_clip_by_global_norm, linear_warmup_cosine
 
     cfg = reduced_config(args.arch) if args.smoke else get_config(args.arch)
-    shape = InputShape("train", args.seq, args.batch, "train")
-    mesh = make_local_mesh() if jax.device_count() == 1 else make_production_mesh()
-
-    opt = chain_clip_by_global_norm(
-        adamw(linear_warmup_cosine(args.lr, 20, max(args.steps, 100)), weight_decay=0.1),
-        max_norm=1.0,
-    )
-    ctx = SPMDTrainContext(cfg, opt, mesh)
-    learner = SPMDLearnerWorker(ctx)
-
-    pipes = ActorPool.from_targets(
-        [
-            TokenPipeline(cfg, shape, seed=0, host_id=i, num_hosts=args.data_shards)
-            for i in range(args.data_shards)
-        ],
-        name="data",
-    )
-    workers = WorkerSet(learner, pipes)
-    spec = build_lm_flow(workers, pipes)
     if args.dot:
-        print(spec.to_dot())
+        workers, pipes, _ = build_trainer(
+            cfg, args.batch, args.seq, args.data_shards, args.lr, args.steps
+        )
+        print(build_lm_flow(workers, pipes).to_dot())
         return
 
     t0 = time.time()
-    with Algorithm.from_plan(spec, workers) as algo:
-        for step in range(args.steps):
-            res = algo.train()
-            loss = res["info"].get("loss", float("nan"))
-            if step % max(1, args.steps // 10) == 0 or step == args.steps - 1:
-                print(
-                    f"step {step:4d} loss {loss:.4f} "
-                    f"({(time.time() - t0) / (step + 1):.2f}s/step)",
-                    flush=True,
-                )
-        if args.checkpoint:
-            save_pytree(args.checkpoint, learner.params)
-            print(f"saved checkpoint to {args.checkpoint}")
+
+    def report(step: int, loss: float) -> None:
+        if step % max(1, args.steps // 10) == 0 or step == args.steps - 1:
+            print(
+                f"step {step:4d} loss {loss:.4f} "
+                f"({(time.time() - t0) / (step + 1):.2f}s/step)",
+                flush=True,
+            )
+
+    _, learner = train(
+        cfg, args.steps, args.batch, args.seq, args.data_shards, args.lr, on_step=report
+    )
+    if args.checkpoint:
+        save_pytree(args.checkpoint, learner.params)
+        print(f"saved checkpoint to {args.checkpoint}")
 
 
 if __name__ == "__main__":

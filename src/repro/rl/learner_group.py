@@ -16,6 +16,11 @@ a jit-compiled SPMD step:
     ``device_put`` directly with a ``NamedSharding`` over the mesh's
     ``data`` axis (resolved through the existing ``AxisRules`` table), so
     each device receives only its slice; no full-batch staging on device 0.
+  * **per-device gradients** — the loss and its gradient run under
+    ``shard_map``, one program per device over its rows, then ``pmean``
+    over ``data``.  XLA cannot partition a Pallas TPU kernel, so a loss
+    that calls one (flash attention, the fused PPO surrogate) must be
+    written per device rather than left to the SPMD partitioner.
   * **gradient microbatch accumulation** — the per-device shard is split
     into ``microbatch`` slices walked by ``lax.scan``, accumulating the
     mean gradient before a single optimizer apply: global batch sizes
@@ -123,8 +128,17 @@ class ShardedLearnerGroup:
         optimizer = self.worker.optimizer
         loss_for = self.worker._loss_for
         k = self.microbatch
+        n = self.num_learners
+        # Per-row aux leaves (td_error) carry the row axis; scalar ones
+        # (stacked to [k] under microbatching) are means over rows.
+        scalar_ndim = 1 if k > 1 else 0
+        row_spec = P(None, "data") if k > 1 else P("data")
 
-        def step(params, target_params, opt_state, batch, key):
+        def local_grads(params, target_params, batch, key):
+            """One device's shard: mean gradient and loss over its rows."""
+            if n > 1:
+                # Shards draw independent noise (stochastic losses, SAC).
+                key = jax.random.fold_in(key, jax.lax.axis_index("data"))
             if k > 1:
                 def microstep(carry, mb):
                     grad_acc, loss_acc, key = carry
@@ -147,8 +161,30 @@ class ShardedLearnerGroup:
                 (loss, aux), grads = jax.value_and_grad(loss_for, has_aux=True)(
                     params, target_params, batch, key
                 )
+            # Equal shards of a row-mean loss: the global mean is the mean
+            # of the shard means.
+            mean = lambda x: jax.lax.pmean(x, "data")  # noqa: E731
+            scalars = {a: mean(v) for a, v in aux.items() if v.ndim <= scalar_ndim}
+            rows = {a: v for a, v in aux.items() if v.ndim > scalar_ndim}
+            return jax.tree_util.tree_map(mean, grads), mean(loss), scalars, rows
+
+        # Pallas TPU kernels in the loss cannot be partitioned by XLA, so
+        # the gradient is an explicit per-device program (shard_map) whose
+        # results are averaged over the data axis.
+        sharded_grads = jax.shard_map(
+            local_grads,
+            mesh=self.mesh,
+            in_specs=(P(), P(), row_spec, P()),
+            out_specs=(P(), P(), P(), row_spec),
+            check_vma=False,
+        )
+
+        def step(params, target_params, opt_state, batch, key):
+            grads, loss, scalars, rows = sharded_grads(
+                params, target_params, batch, key
+            )
             params, opt_state = optimizer.apply(params, grads, opt_state)
-            return params, opt_state, loss, aux
+            return params, opt_state, loss, {**scalars, **rows}
 
         return jax.jit(
             step,

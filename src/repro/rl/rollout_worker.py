@@ -175,8 +175,10 @@ class RolloutWorker:
         self.env_state, self.obs, self._ep_returns, cols = self._rollout_jit(
             self.params, self.env_state, self.obs, self._ep_returns, k
         )
+        # Select by the done mask, not by a nonzero return: an episode whose
+        # return is exactly 0 (a sparse reward) is still a completed episode.
         completed = np.asarray(cols.pop("completed"))
-        for r in completed[completed != 0.0]:
+        for r in completed[np.asarray(cols["dones"]) != 0.0]:
             self._completed.append(float(r))
         if self.algo in ("dqn", "sac"):
             for k_ in ("logp", "values"):
@@ -514,14 +516,15 @@ class VectorizedRolloutWorker(RolloutWorker):
             cols["returns"] = ret
         return cols
 
-    def _record_completed(self, completed: np.ndarray) -> None:
-        for r in completed.T.reshape(-1)[completed.T.reshape(-1) != 0.0]:
+    def _record_completed(self, completed: np.ndarray, dones: np.ndarray) -> None:
+        # Lane-major; zero-return episodes count (see RolloutWorker.sample).
+        for r in completed.T.reshape(-1)[dones.T.reshape(-1) != 0.0]:
             self._completed.append(float(r))
 
     def _emit(self, cols: Dict[str, Any]) -> SampleBatch:
         """Post-scan host path shared by all inference modes."""
         cols = dict(self._postprocess_jit(self.params, cols))
-        self._record_completed(np.asarray(cols.pop("completed")))
+        self._record_completed(np.asarray(cols.pop("completed")), np.asarray(cols["dones"]))
         if self.algo in ("dqn", "sac"):
             for k_ in ("logp", "values"):
                 cols.pop(k_, None)

@@ -141,3 +141,32 @@ def test_local_mesh_train_step_runs():
         tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
         p2, o2, m = step(params, opt.init(params), {"tokens": tokens, "labels": tokens})
         assert np.isfinite(float(m["loss"]))
+
+
+@pytest.mark.timeout(300)
+def test_train_mesh_spans_every_local_device():
+    """``launch/train`` builds an ``(n, 1)`` data x model mesh from the
+    devices present: on a 4-device host (simulated, in a child process so
+    the device count is fixed before JAX starts) all four chips hold the
+    data-parallel learner, and the LM flow trains."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import json, math\n"
+        "from repro.configs import reduced_config\n"
+        "from repro.launch.train import train\n"
+        "losses, learner = train(reduced_config('qwen3-14b', d_model=64), steps=2, batch=4, seq=16)\n"
+        "print(json.dumps({'mesh': dict(learner.ctx.mesh.shape),\n"
+        "                  'finite': all(math.isfinite(x) for x in losses)}))\n"
+    )
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=280)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    row = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert row == {"mesh": {"data": 4, "model": 1}, "finite": True}

@@ -357,3 +357,39 @@ def test_failure_policy_validation():
     assert FailurePolicy.validate("drop_shard") == "drop_shard"
     with pytest.raises(ValueError):
         FailurePolicy.validate("explode")
+
+
+# ------------------------------------------------------ one process per chip
+class EnvProbe:
+    """Reports the JAX platform setting its process was started with."""
+
+    def platforms(self):
+        import os
+
+        return os.environ.get("JAX_PLATFORMS")
+
+
+def test_actor_children_stay_off_the_chip(monkeypatch):
+    """Actor children are CPU actors: a parent pinned to the CPU keeps the
+    requested (or fast fork) start method, while a parent that may hold an
+    accelerator always spawns them, with JAX_PLATFORMS=cpu in the child's
+    environment and its own environment left as it was."""
+    import os
+
+    from repro.core import executor as ex
+
+    monkeypatch.setattr(ex, "_parent_on_cpu", lambda: True)
+    assert ex.child_start_method("forkserver") == "forkserver"
+    assert ex.child_start_method() in ("fork", "spawn")
+    monkeypatch.setattr(ex, "_parent_on_cpu", lambda: False)
+    assert ex.child_start_method("fork") == "spawn"
+    assert ex.child_start_method() == "spawn"
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    cell = ex.ProcessCell(target=EnvProbe(), start_method="fork", transport="pickle")
+    try:
+        assert cell._ctx.get_start_method() == "spawn"
+        assert cell.rpc("platforms", (), {}) == "cpu"
+        assert "JAX_PLATFORMS" not in os.environ
+    finally:
+        cell.stop()

@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps vs pure-jnp oracles, in
-interpret mode (CPU container; same code compiles on TPU)."""
+interpret mode on the CPU.  Interpret mode does not check TPU tiling or
+VMEM limits; ``tests/test_tpu_compile.py`` compiles the main-path kernels
+for a described v5e chip."""
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +40,32 @@ def test_flash_attention_sweep(S, H, KV, D, dtype, causal, window):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=tol, rtol=tol
     )
+
+
+@pytest.mark.parametrize("S,H,KV,D", [(128, 4, 4, 64), (256, 4, 2, 64)])
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 64), (False, 0)])
+def test_flash_attention_grad_parity(S, H, KV, D, causal, window):
+    """The custom_vjp backward (recompute through the chunked oracle) gives
+    the gradients of the oracle itself, alongside a kernel forward."""
+    B = 2
+    q = _rand(0, (B, S, H, D), jnp.float32)
+    k = _rand(1, (B, S, KV, D), jnp.float32)
+    v = _rand(2, (B, S, KV, D), jnp.float32)
+    ct = _rand(3, (B, S, H, D), jnp.float32)
+
+    def kernel_loss(q, k, v):
+        out = flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                     block_q=64, block_k=64, interpret=True)
+        return jnp.sum(out * ct)
+
+    def ref_loss(q, k, v):
+        return jnp.sum(naive_attention(q, k, v, causal=causal, window=window) * ct)
+
+    val, grads = jax.value_and_grad(kernel_loss, argnums=(0, 1, 2))(q, k, v)
+    ref_val, ref_grads = jax.value_and_grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(val), float(ref_val), atol=1e-5, rtol=1e-5)
+    for g, rg in zip(grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(rg), atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("W,H,KV,D", [(256, 8, 2, 64), (512, 4, 4, 128), (128, 8, 8, 64)])

@@ -233,6 +233,24 @@ def test_worker_cache_decode_sample_columns():
     assert stats["episodes"] >= 0
 
 
+def test_zero_return_episodes_are_counted():
+    """A sparse reward is often exactly 0: such episodes still complete and
+    must enter the episode count and mean (they used to be dropped as if
+    the lane had not finished)."""
+    from repro.optim import adam
+
+    env = TokenEnv(vocab_size=16, ctx=16, horizon=4, reward_fn=lambda *a: jnp.float32(0.0))
+    policy = LMTokenPolicy(ctx=16, vocab_size=16, d_model=16, n_layers=1)
+    w = VectorizedRolloutWorker(
+        env, policy, algo="ppo", num_envs=4, rollout_len=8,
+        optimizer=adam(1e-3), seed=0, worker_index=1, decode="cache",
+    )
+    w.sample()
+    stats = w.episode_stats()
+    assert stats["episodes"] == 8  # 4 lanes x two horizon-4 episodes
+    assert stats["episode_reward_mean"] == 0.0
+
+
 def test_worker_cache_decode_state_roundtrip():
     w1 = make_rlhf_worker(0, num_envs=4, rollout_len=8, d_model=16, n_layers=1)
     w1.sample()
