@@ -27,6 +27,7 @@ from repro.core.metrics import (
     QUEUE_OCCUPANCY_PREFIX,
     get_metrics,
     payload_nbytes,
+    span,
 )
 from repro.core.transport import OverflowPolicy
 
@@ -137,18 +138,19 @@ class Enqueue:
                 # credit, however briefly — record it, then wait it out.
                 stalled_at = time.perf_counter()
                 metrics.counters[NUM_CREDIT_STALLS] += 1
-                while self.check is None or self.check():
-                    try:
-                        # Re-stamp per attempt: the queue-wait metric must
-                        # measure residency in the queue, not this
-                        # producer-side credit stall (already counted).
-                        self._stamp(item)
-                        self.queue.put(item, timeout=0.05)
-                        break
-                    except queue.Full:
-                        continue
-                else:
-                    raise RuntimeError("Enqueue check failed: consumer is dead")
+                with span("flow.enqueue"):
+                    while self.check is None or self.check():
+                        try:
+                            # Re-stamp per attempt: the queue-wait metric
+                            # must measure residency in the queue, not this
+                            # producer-side credit stall (already counted).
+                            self._stamp(item)
+                            self.queue.put(item, timeout=0.05)
+                            break
+                        except queue.Full:
+                            continue
+                    else:
+                        raise RuntimeError("Enqueue check failed: consumer is dead")
                 metrics.counters[CREDIT_STALL_TIME] = (
                     metrics.counters.get(CREDIT_STALL_TIME, 0)
                     + (time.perf_counter() - stalled_at)
@@ -205,7 +207,8 @@ def Dequeue(
             if check is not None and not check():
                 raise RuntimeError("Dequeue check failed: producer is dead")
             try:
-                item = in_queue.get(timeout=0.05)
+                with span("flow.dequeue"):
+                    item = in_queue.get(timeout=0.05)
             except queue.Empty:
                 yield NextValueNotReady()
                 continue

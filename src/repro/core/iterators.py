@@ -70,6 +70,7 @@ from repro.core.metrics import (
     get_metrics,
     payload_nbytes,
     set_metrics_for_thread,
+    span,
 )
 
 T = TypeVar("T")
@@ -681,40 +682,37 @@ class ParallelIterator(Generic[T]):
                     if dropped:
                         raise RuntimeError(f"{self.name}: all shards failed")
                     return
-                # Dispatch defensively: an actor stopped mid-round (elastic
-                # remove_workers race / teardown) is skipped, but futures
-                # already dispatched this round are still gathered so their
-                # items are never silently discarded.
-                round_start = time.perf_counter()
-                futures = []
-                for s in shards:
-                    try:
-                        futures.append((s, s.dispatch(self._stages_for(s.actor))))
-                    except RuntimeError:
-                        pass
+                # One round, dispatch -> barrier -> gathered, timed under the
+                # node id: the stage's live wall-time column in
+                # Algorithm.explain() (for a rollouts source this is the
+                # sample time the flow actually observed).
+                with span("rollout.gather", timer=timer):
+                    # Dispatch defensively: an actor stopped mid-round
+                    # (elastic remove_workers race / teardown) is skipped,
+                    # but futures already dispatched this round are still
+                    # gathered so their items are never silently discarded.
+                    futures = []
+                    for s in shards:
+                        try:
+                            futures.append((s, s.dispatch(self._stages_for(s.actor))))
+                        except RuntimeError:
+                            pass
+                    # Global barrier: wait for every shard's item.
+                    results = []
+                    for s, f in futures:
+                        try:
+                            item = f.result()
+                        except StopIteration:
+                            item = _EXHAUSTED
+                        except Exception as exc:
+                            item = _absorb_shard_failure(s.actor, exc, dropped, self.name)
+                        results.append((item, s.actor))
                 if not futures:
                     if self._sync_shards():
                         continue  # membership changed: retry with survivors
                     return  # all actors stopped: stream teardown
-                # Global barrier: wait for every shard's item.
-                results = []
-                for s, f in futures:
-                    try:
-                        item = f.result()
-                    except StopIteration:
-                        item = _EXHAUSTED
-                    except Exception as exc:
-                        item = _absorb_shard_failure(s.actor, exc, dropped, self.name)
-                    results.append((item, s.actor))
                 if any(isinstance(item, _Exhausted) for item, _ in results):
                     return
-                # Per-round wall time of the dispatch -> barrier -> gathered
-                # window, keyed by node id: the stage's live wall-time column
-                # in Algorithm.explain() (for a rollouts source this is the
-                # sample time the flow actually observed).
-                get_metrics().timers[GATHER_TIMER_PREFIX + key].push(
-                    time.perf_counter() - round_start
-                )
                 for item, actor in results:
                     if isinstance(item, (NextValueNotReady, _ShardVerdict)):
                         continue
@@ -727,6 +725,7 @@ class ParallelIterator(Generic[T]):
                     yield item
 
         key = metrics_key or f"{self.name}.gather_sync"
+        timer = GATHER_TIMER_PREFIX + key
         return LocalIterator(_gen, name=f"{self.name}.gather_sync")
 
     def gather_async(
@@ -843,21 +842,33 @@ class ParallelIterator(Generic[T]):
                         starved.pop(aid, None)
                 credit_pool.resize(_capacity())
 
+            def _next_result() -> Optional[tuple]:
+                # Wait for the next completed shard item; None once no live
+                # shard is left.
+                while True:
+                    _admit()  # cheap (pool version compare); elastic sync point
+                    if sum(inflight.values()) == 0:
+                        active = set(shard_by_id) - set(dropped) - exhausted - removed
+                        if not active:
+                            if dropped and not (exhausted or removed):
+                                raise RuntimeError(f"{self.name}: all shards failed")
+                            return None
+                        if starved:
+                            _backfill_starved()  # window freed below a live shard
+                    try:
+                        return result_q.get(timeout=0.1)
+                    except queue.Empty:
+                        continue  # elastic wake-up: re-check membership
+
             _admit()
             while True:
-                _admit()  # cheap (pool version compare); elastic sync point
-                if sum(inflight.values()) == 0:
-                    active = set(shard_by_id) - set(dropped) - exhausted - removed
-                    if not active:
-                        if dropped and not (exhausted or removed):
-                            raise RuntimeError(f"{self.name}: all shards failed")
-                        return
-                    if starved:
-                        _backfill_starved()  # window freed below a live shard
-                try:
-                    aid, fut = result_q.get(timeout=0.1)
-                except queue.Empty:
-                    continue  # elastic wake-up: re-check membership
+                # One span (and timer push) per wait for a shard's item,
+                # across the elastic wake-ups.
+                with span("rollout.gather", timer=timer):
+                    got = _next_result()
+                if got is None:
+                    return
+                aid, fut = got
                 inflight[aid] -= 1
                 credit_pool.release()  # every popped result frees its credit
                 gone = aid in dropped or aid in removed
@@ -903,6 +914,7 @@ class ParallelIterator(Generic[T]):
                 _backfill_starved()
 
         key = metrics_key or f"{self.name}.gather_async"
+        timer = GATHER_TIMER_PREFIX + key
         return LocalIterator(_gen, name=f"{self.name}.gather_async")
 
     def batch_across_shards(
@@ -920,36 +932,34 @@ class ParallelIterator(Generic[T]):
                     if dropped:
                         raise RuntimeError(f"{self.name}: all shards failed")
                     return
-                # Defensive dispatch: see gather_sync — skip actors stopped
-                # mid-round but never abandon already-dispatched futures.
-                round_start = time.perf_counter()
-                futures = []
-                for s in shards:
-                    try:
-                        futures.append((s, s.dispatch(self._stages_for(s.actor))))
-                    except RuntimeError:
-                        pass
+                # Same per-round gather span and timer as gather_sync (see
+                # there); for a bulk_sync rollouts source this is the
+                # observed sample time.
+                with span("rollout.gather", timer=timer):
+                    # Defensive dispatch: see gather_sync — skip actors
+                    # stopped mid-round but never abandon dispatched futures.
+                    futures = []
+                    for s in shards:
+                        try:
+                            futures.append((s, s.dispatch(self._stages_for(s.actor))))
+                        except RuntimeError:
+                            pass
+                    items = []
+                    for s, f in futures:
+                        try:
+                            items.append(f.result())
+                        except StopIteration:
+                            items.append(_EXHAUSTED)
+                        except Exception as exc:
+                            items.append(
+                                _absorb_shard_failure(s.actor, exc, dropped, self.name)
+                            )
                 if not futures:
                     if self._sync_shards():
                         continue
                     return
-                items = []
-                for s, f in futures:
-                    try:
-                        items.append(f.result())
-                    except StopIteration:
-                        items.append(_EXHAUSTED)
-                    except Exception as exc:
-                        items.append(
-                            _absorb_shard_failure(s.actor, exc, dropped, self.name)
-                        )
                 if any(isinstance(x, _Exhausted) for x in items):
                     return
-                # Same per-round gather timer as gather_sync (see there); for
-                # a bulk_sync rollouts source this is the observed sample time.
-                get_metrics().timers[GATHER_TIMER_PREFIX + key].push(
-                    time.perf_counter() - round_start
-                )
                 items = [
                     x for x in items
                     if not isinstance(x, (NextValueNotReady, _ShardVerdict))
@@ -963,6 +973,7 @@ class ParallelIterator(Generic[T]):
                     yield items
 
         key = metrics_key or f"{self.name}.batch_across_shards"
+        timer = GATHER_TIMER_PREFIX + key
         return LocalIterator(_gen, name=f"{self.name}.batch_across_shards")
 
     def __repr__(self) -> str:  # pragma: no cover

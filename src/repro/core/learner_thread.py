@@ -13,7 +13,12 @@ learned records
     host) to the moment the learner picks it up;
   * ``learner_queue_wait_s`` — time spent waiting in the in-queue (stamped
     by ``Enqueue``);
+  * ``policy_lag`` — updates between the batch's weights and its update
+    (recorded by ``learn_on_batch``);
   * ``queue_occupancy/learner_in|learner_out`` gauges.
+
+The thread's wait for each item is the ``learner.wait`` span: one span from
+the start of waiting to the item's arrival.
 
 The out-queue applies an overflow policy (``drop_newest`` keeps the paper's
 lossy metrics behaviour; ``drop_oldest``/``block`` are available for flows
@@ -32,7 +37,8 @@ from repro.core.metrics import (
     QUEUE_OCCUPANCY_PREFIX,
     SAMPLE_TO_LEARN_LATENCY,
     MetricsContext,
-    TimerStat,
+    set_metrics_for_thread,
+    span,
 )
 from repro.core.transport import OverflowPolicy
 
@@ -79,7 +85,6 @@ class LearnerThread(threading.Thread):
         self.out_policy = OverflowPolicy.validate(out_policy)
         self.weights_updated = False
         self.stopped = False
-        self.learn_timer = TimerStat()
         self.num_steps = 0
         self.num_out_dropped = 0
         # Shared metrics context of the owning flow; assigned by
@@ -87,11 +92,14 @@ class LearnerThread(threading.Thread):
         self.metrics: Optional[MetricsContext] = None
 
     def run(self) -> None:
+        if self.metrics is not None:
+            # Learner-side stats (policy_lag) land in the flow's context.
+            set_metrics_for_thread(self.metrics)
         while not self.stopped:
-            try:
-                item = self.inqueue.get(timeout=0.1)
-            except queue.Empty:
-                continue
+            with span("learner.wait"):
+                item = self._next_item()
+            if item is None:
+                continue  # stopped while waiting
             t_pickup = time.perf_counter()
             # Items may be (batch, replay_actor) pairs or bare batches.
             if isinstance(item, tuple) and len(item) == 2:
@@ -104,11 +112,20 @@ class LearnerThread(threading.Thread):
                 if self.learner_group is not None
                 else self.local_worker.learn_on_batch
             )
-            with self.learn_timer:
-                info = learn(batch)
+            info = learn(batch)
             self.weights_updated = True
             self.num_steps += 1
             self._put_out((source_actor, batch, info))
+
+    def _next_item(self) -> Any:
+        """The next in-queue item, polled every 0.1 s so ``stop()`` is seen;
+        None once stopped."""
+        while not self.stopped:
+            try:
+                return self.inqueue.get(timeout=0.1)
+            except queue.Empty:
+                continue
+        return None
 
     def _record_latency(self, batch: Any, t_pickup: float) -> None:
         if self.metrics is None:

@@ -26,6 +26,7 @@ from repro.core.metrics import (
     STEPS_TRAINED_COUNTER,
     TARGET_NET_UPDATES,
     get_metrics,
+    span,
 )
 from repro.core.workers import WorkerSet
 from repro.rl.sample_batch import MultiAgentBatch, SampleBatch
@@ -349,12 +350,16 @@ class TrainOneStep:
     def __call__(self, batch: Any) -> Any:
         metrics = get_metrics()
         lw = self.workers.local_worker()
-        with metrics.timers[LEARN_ON_BATCH_TIMER]:
+        with span("learner.train_one_step", timer=LEARN_ON_BATCH_TIMER):
             if self.num_sgd_iter > 1 or self.sgd_minibatch_size:
                 infos = []
                 mbs = self.sgd_minibatch_size or batch.count
+                start = getattr(lw, "weights_version", None)
                 for _ in range(self.num_sgd_iter):
                     for mb in batch.minibatches(mbs, self._rng):
+                        if start is not None and mb.weights_version is not None:
+                            # The batch's own SGD passes are not policy lag.
+                            mb.weights_version += lw.weights_version - start
                         infos.append(self._learn(lw, mb))
                 info = infos[-1] if infos else {}
             else:
@@ -576,6 +581,10 @@ class ReportMetrics:
         self._remote_has_stats: Optional[bool] = None
 
     def __call__(self, item: Any) -> Dict[str, Any]:
+        with span("flow.report"):
+            return self._report(item)
+
+    def _report(self, item: Any) -> Dict[str, Any]:
         metrics = get_metrics()
         info = item[1] if isinstance(item, tuple) and len(item) == 2 else item
         result = dict(metrics.save())

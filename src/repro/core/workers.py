@@ -31,6 +31,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 from repro.core.actor import ActorPool, VirtualActor
 from repro.core.executor import FailurePolicy
+from repro.core.metrics import span
 
 __all__ = ["WorkerSet"]
 
@@ -140,31 +141,42 @@ class WorkerSet:
         worker cannot poison a TrainOneStep weight broadcast.  Workers under
         the default RAISE policy keep the legacy global-barrier semantics:
         their failure propagates to the driver.
+
+        A local worker that keeps a ``weights_version`` sends it with the
+        weights, so samplers stamp their batches with the version they hold.
         """
+        args = self._weights_args()
+        with span("weight_sync"):
+            futures = []
+            for actor in self._remote:
+                if not getattr(actor, "alive", True):
+                    continue
+                try:
+                    futures.append((actor, actor.call("set_weights", *args)))
+                except RuntimeError:
+                    continue  # stopped between the alive check and the call
+            for actor, f in futures:
+                try:
+                    f.result()
+                except Exception as exc:
+                    policy = getattr(actor, "failure_policy", FailurePolicy.RAISE)
+                    if policy == FailurePolicy.RAISE and getattr(actor, "alive", True):
+                        raise
+                    logger.warning("sync_weights: worker %s failed: %s", actor.name, repr(exc))
+            for sink in self._weight_sinks:
+                try:
+                    sink(args[0])
+                except Exception as exc:
+                    # Sinks heal themselves (InferenceClient.recover); a dead
+                    # server must not poison a rollout-worker broadcast.
+                    logger.warning("sync_weights: weight sink failed: %s", repr(exc))
+
+    def _weights_args(self) -> tuple:
+        """``set_weights`` arguments: the local weights, and their version
+        where the local worker keeps one."""
         weights = self._local.get_weights()
-        futures = []
-        for actor in self._remote:
-            if not getattr(actor, "alive", True):
-                continue
-            try:
-                futures.append((actor, actor.call("set_weights", weights)))
-            except RuntimeError:
-                continue  # stopped between the alive check and the call
-        for actor, f in futures:
-            try:
-                f.result()
-            except Exception as exc:
-                policy = getattr(actor, "failure_policy", FailurePolicy.RAISE)
-                if policy == FailurePolicy.RAISE and getattr(actor, "alive", True):
-                    raise
-                logger.warning("sync_weights: worker %s failed: %s", actor.name, repr(exc))
-        for sink in self._weight_sinks:
-            try:
-                sink(weights)
-            except Exception as exc:
-                # Sinks heal themselves (InferenceClient.recover); a dead
-                # server must not poison a rollout-worker broadcast.
-                logger.warning("sync_weights: weight sink failed: %s", repr(exc))
+        version = getattr(self._local, "weights_version", None)
+        return (weights,) if version is None else (weights, version)
 
     def add_weight_sink(self, sink: Callable[[Any], None]) -> None:
         """Register an extra weight-broadcast consumer (e.g. the decoupled
@@ -187,11 +199,11 @@ class WorkerSet:
         if self._factory is None:
             raise RuntimeError("WorkerSet has no factory; build it with WorkerSet.create")
         added = []
-        weights = self._local.get_weights()
+        args = self._weights_args()
         for _ in range(num_workers):
             actor = self._make_actor(self._factory, self._next_index, self._actor_kwargs)
             self._next_index += 1
-            actor.call("set_weights", weights)
+            actor.call("set_weights", *args)
             self._remote.add(actor)
             added.append(actor)
         return added

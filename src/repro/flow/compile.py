@@ -30,6 +30,7 @@ from repro.core.iterators import (
     from_items,
 )
 from repro.core.learner_thread import LearnerThread
+from repro.core.metrics import span
 from repro.core.operators import (
     ParallelRollouts,
     Replay,
@@ -143,6 +144,19 @@ def compose_stages(fns: Sequence[Callable]) -> Callable:
     fused.__name__ = f"fused[{len(fns)}]"
     fused.flow_pure = all(is_pure(f) for f in fns)
     return fused
+
+
+def _spanned(fn: Callable, label: str) -> Callable:
+    """``fn`` under the program span ``flow.<label>`` (named once, here)."""
+    name = f"flow.{label}"
+
+    def stage(item: Any) -> Any:
+        with span(name):
+            return fn(item)
+
+    stage.__name__ = label
+    stage.flow_pure = is_pure(fn)
+    return stage
 
 
 # --------------------------------------------------------------------------
@@ -683,7 +697,9 @@ class CompiledFlow:
                 return up
             fns = [self._instantiate(s) for s in p["stages"]]
             self._lower_learner_annotations(node, fns)
-            return up.for_each(compose_stages(fns))
+            return up.for_each(
+                compose_stages([_spanned(fn, s.label) for fn, s in zip(fns, p["stages"])])
+            )
         if k == "filter":
             return up.filter(p["predicate"])
         if k == "zip_source_actor":

@@ -117,6 +117,7 @@ def _panel_call(kernel, inputs, T, B, dtype, num_outputs, interpret, block_b):
         out_specs=[spec_tb] * num_outputs,
         out_shape=[jax.ShapeDtypeStruct((T, Bp), dtype)] * num_outputs,
         interpret=interpret,
+        name="advantages",
     )(*padded)
     return [o[:, :B] for o in outs]
 

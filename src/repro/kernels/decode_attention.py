@@ -160,6 +160,7 @@ def decode_attention_pallas(
             pltpu.VMEM((H, KVD), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(
         q.reshape(B, H, D),
         k_cache.reshape(B, W, KVD),

@@ -145,6 +145,7 @@ def _flash_forward(q, k, v, causal, window, q_offset, block_q, block_k, interpre
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
 

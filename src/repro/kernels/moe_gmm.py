@@ -67,4 +67,5 @@ def moe_gmm_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, F), x.dtype),
         interpret=interpret,
+        name="moe_gmm",
     )(eids, x, w)

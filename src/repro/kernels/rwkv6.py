@@ -117,6 +117,7 @@ def rwkv6_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
+        name="rwkv6",
     )(tm(r), tm(k), tm(v), tm(w), u)
     out = out.transpose(0, 2, 1, 3)
     return out, s_out

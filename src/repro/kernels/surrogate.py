@@ -155,6 +155,7 @@ def _panel_call(kernel, inputs, out_rows, B, dtype, interpret, block_b):
         out_specs=[_spec(r) for r in out_rows],
         out_shape=[jax.ShapeDtypeStruct((r, Bp), dtype) for r in out_rows],
         interpret=interpret,
+        name="surrogate",
     )(*padded)
     return [o[:, :B] for o in outs]
 

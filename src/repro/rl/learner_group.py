@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core.metrics import policy_lag, span
 from repro.distributed.sharding import AxisRules, make_data_mesh
 
 PyTree = Any
@@ -244,20 +245,28 @@ class ShardedLearnerGroup:
     def learn_on_batch(self, batch: Any, policy_id: Optional[str] = None) -> Dict[str, Any]:
         if self._step is None:
             self._step = self._build_step()
-        device_batch, usable = self.shard_batch(batch)
-        count = batch.count if hasattr(batch, "count") else usable
         w = self.worker
-        w._key, key = jax.random.split(w._key)
-        w.params, w.opt_state, loss, aux = self._step(
-            w.params, w.target_params, w.opt_state, device_batch, key
-        )
-        self.num_steps += 1
-        # Replay the worker's own per-update side effects (SAC polyak
-        # target tracking — skipping it would train against a frozen
-        # target forever, silently), then keep the touched state on-mesh.
-        if hasattr(w, "_post_update"):
-            w._post_update()
-            w.target_params = jax.device_put(w.target_params, self._replicated)
+        with span("learner.learn", **policy_lag(getattr(w, "weights_version", None), batch)):
+            with span("learner.h2d"):
+                device_batch, usable = self.shard_batch(batch)
+            with span("learner.step"):
+                w._key, key = jax.random.split(w._key)
+                w.params, w.opt_state, loss, aux = self._step(
+                    w.params, w.target_params, w.opt_state, device_batch, key
+                )
+            self.num_steps += 1
+            # Replay the worker's own per-update side effects (the weight
+            # version, SAC polyak target tracking — skipping it would train
+            # against a frozen target forever, silently), then keep the
+            # touched state on-mesh.
+            if hasattr(w, "_post_update"):
+                w._post_update()
+                w.target_params = jax.device_put(w.target_params, self._replicated)
+            with span("learner.fetch"):
+                return self._info(batch, usable, loss, aux)
+
+    def _info(self, batch: Any, usable: int, loss: Any, aux: Dict[str, Any]) -> Dict[str, Any]:
+        count = batch.count if hasattr(batch, "count") else usable
         info: Dict[str, Any] = {"loss": float(loss)}
         for name, v in aux.items():
             if name == "td_error":

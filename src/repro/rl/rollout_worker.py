@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.metrics import policy_lag, span
 from repro.kernels.ops import fused_gae as gae
 from repro.optim import Optimizer, adam
 from repro.rl.env import Env, VectorEnv, VectorEnvState
@@ -114,6 +115,10 @@ class RolloutWorker:
         self.opt_state = self.optimizer.init(self.params)
 
         self._completed: deque = deque(maxlen=100)
+        # The weight version: updates applied to ``params`` here, or the
+        # version that ``set_weights`` delivered with them.  Samplers stamp
+        # it on every batch; the learner's lag is its own minus the batch's.
+        self.weights_version = 0
         self._init_env_state(ek)
 
         self._learn_jit = jax.jit(self._learn)
@@ -171,19 +176,23 @@ class RolloutWorker:
         return env_state, obs, ep_ret, cols
 
     def sample(self) -> SampleBatch:
-        self._key, k = jax.random.split(self._key)
-        self.env_state, self.obs, self._ep_returns, cols = self._rollout_jit(
-            self.params, self.env_state, self.obs, self._ep_returns, k
-        )
-        # Select by the done mask, not by a nonzero return: an episode whose
-        # return is exactly 0 (a sparse reward) is still a completed episode.
-        completed = np.asarray(cols.pop("completed"))
-        for r in completed[np.asarray(cols["dones"]) != 0.0]:
-            self._completed.append(float(r))
-        if self.algo in ("dqn", "sac"):
-            for k_ in ("logp", "values"):
-                cols.pop(k_, None)
-        return _to_numpy_batch(cols)
+        with span("rollout.sample"):
+            self._key, k = jax.random.split(self._key)
+            self.env_state, self.obs, self._ep_returns, cols = self._rollout_jit(
+                self.params, self.env_state, self.obs, self._ep_returns, k
+            )
+            # Select by the done mask, not by a nonzero return: an episode
+            # whose return is exactly 0 (a sparse reward) is still a
+            # completed episode.
+            completed = np.asarray(cols.pop("completed"))
+            for r in completed[np.asarray(cols["dones"]) != 0.0]:
+                self._completed.append(float(r))
+            if self.algo in ("dqn", "sac"):
+                for k_ in ("logp", "values"):
+                    cols.pop(k_, None)
+            batch = _to_numpy_batch(cols)
+        batch.weights_version = self.weights_version
+        return batch
 
     def sample_with_count(self) -> Tuple[SampleBatch, int]:
         b = self.sample()
@@ -226,23 +235,30 @@ class RolloutWorker:
         }
 
     def learn_on_batch(self, batch: SampleBatch, policy_id: Optional[str] = None) -> Dict[str, Any]:
-        self._key, k = jax.random.split(self._key)
-        self.params, self.opt_state, loss, aux = self._learn_jit(
-            self.params, self.target_params, self.opt_state, self._device_batch(batch), k
-        )
-        info = {"loss": float(loss)}
-        for name, v in aux.items():
-            if name == "td_error":
-                info["td_error"] = np.asarray(v)
-            else:
-                info[name] = float(v)
-        self._post_update()
+        with span("learner.learn", **policy_lag(self.weights_version, batch)):
+            self._key, k = jax.random.split(self._key)
+            with span("learner.h2d"):
+                device_batch = self._device_batch(batch)
+            with span("learner.step"):
+                self.params, self.opt_state, loss, aux = self._learn_jit(
+                    self.params, self.target_params, self.opt_state, device_batch, k
+                )
+            with span("learner.fetch"):
+                info = {"loss": float(loss)}
+                for name, v in aux.items():
+                    if name == "td_error":
+                        info["td_error"] = np.asarray(v)
+                    else:
+                        info[name] = float(v)
+            self._post_update()
         return info
 
     def _post_update(self) -> None:
         """Per-update side effects beyond the optimizer step (single hook so
-        sharded learner groups replay the exact same behaviour): SAC tracks
-        its target network by polyak averaging."""
+        sharded learner groups replay the exact same behaviour): the weight
+        version counts the update, and SAC tracks its target network by
+        polyak averaging."""
+        self.weights_version += 1
         if self.algo == "sac" and self.target_polyak > 0:
             tau = self.target_polyak
             self.target_params = jax.tree_util.tree_map(
@@ -259,13 +275,16 @@ class RolloutWorker:
 
     def apply_gradients(self, grads: PyTree) -> None:
         self.params, self.opt_state = self._apply_jit(self.params, self.opt_state, grads)
+        self.weights_version += 1
 
     # ------------------------------------------------------------- messaging
     def get_weights(self) -> PyTree:
         return self.params
 
-    def set_weights(self, weights: PyTree) -> None:
+    def set_weights(self, weights: PyTree, version: Optional[int] = None) -> None:
         self.params = weights
+        if version is not None:
+            self.weights_version = version
 
     def update_target(self) -> None:
         self.target_params = jax.tree_util.tree_map(jnp.array, self.params)
@@ -523,20 +542,26 @@ class VectorizedRolloutWorker(RolloutWorker):
 
     def _emit(self, cols: Dict[str, Any]) -> SampleBatch:
         """Post-scan host path shared by all inference modes."""
-        cols = dict(self._postprocess_jit(self.params, cols))
-        self._record_completed(np.asarray(cols.pop("completed")), np.asarray(cols["dones"]))
-        if self.algo in ("dqn", "sac"):
-            for k_ in ("logp", "values"):
-                cols.pop(k_, None)
-        return assemble_fragments(cols, self._lane_base)
+        with span("postprocess.bootstrap"):
+            cols = dict(self._postprocess_jit(self.params, cols))
+        with span("rollout.fetch"):
+            self._record_completed(np.asarray(cols.pop("completed")), np.asarray(cols["dones"]))
+            if self.algo in ("dqn", "sac"):
+                for k_ in ("logp", "values"):
+                    cols.pop(k_, None)
+            batch = assemble_fragments(cols, self._lane_base)
+        batch.weights_version = self.weights_version
+        return batch
 
     def sample(self) -> SampleBatch:
-        if self.inference == "server":
-            return self._sample_server()
-        self.vstate, self.act_rng, self.lane_state, cols = self._vrollout_jit(
-            self.params, self.vstate, self.act_rng, self.lane_state
-        )
-        return self._emit(cols)
+        with span("rollout.sample"):
+            if self.inference == "server":
+                return self._sample_server()
+            with span("rollout.scan"):
+                self.vstate, self.act_rng, self.lane_state, cols = self._vrollout_jit(
+                    self.params, self.vstate, self.act_rng, self.lane_state
+                )
+            return self._emit(cols)
 
     # ---------------------------------------------------- decoupled inference
     def _sample_server(self) -> SampleBatch:

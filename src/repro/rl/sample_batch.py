@@ -47,6 +47,10 @@ class SampleBatch(Mapping[str, np.ndarray]):
         # latency from it.  Derived batches inherit/propagate it (slice:
         # same stamp; concat: earliest constituent).
         self.created_at: float = time.perf_counter()
+        # Version of the weights the batch was sampled with (stamped by the
+        # sampler; None where unknown).  Derived batches keep it the same
+        # way: slice the same, concat the oldest.
+        self.weights_version: Optional[int] = None
 
     # Mapping interface -----------------------------------------------------
     def __getitem__(self, k: str) -> np.ndarray:
@@ -80,16 +84,18 @@ class SampleBatch(Mapping[str, np.ndarray]):
             return 0
         return next(iter(self._data.values())).shape[0]
 
-    def slice(self, start: int, end: int) -> "SampleBatch":
-        out = SampleBatch({k: v[start:end] for k, v in self._data.items()})
+    def _derived(self, data: Dict[str, np.ndarray]) -> "SampleBatch":
+        out = SampleBatch(data)
         out.created_at = self.created_at
+        out.weights_version = self.weights_version
         return out
+
+    def slice(self, start: int, end: int) -> "SampleBatch":
+        return self._derived({k: v[start:end] for k, v in self._data.items()})
 
     def shuffle(self, rng: np.random.Generator) -> "SampleBatch":
         perm = rng.permutation(self.count)
-        out = SampleBatch({k: v[perm] for k, v in self._data.items()})
-        out.created_at = self.created_at
-        return out
+        return self._derived({k: v[perm] for k, v in self._data.items()})
 
     def minibatches(self, size: int, rng: Optional[np.random.Generator] = None):
         b = self.shuffle(rng) if rng is not None else self
@@ -120,6 +126,8 @@ class SampleBatch(Mapping[str, np.ndarray]):
         out.created_at = min(
             getattr(b, "created_at", out.created_at) for b in batches
         )
+        versions = [v for b in batches if (v := getattr(b, "weights_version", None)) is not None]
+        out.weights_version = min(versions) if versions else None
         return out
 
     def shard(self, num_shards: int) -> List["SampleBatch"]:
@@ -141,9 +149,7 @@ class SampleBatch(Mapping[str, np.ndarray]):
         return [self.slice(i * rows, (i + 1) * rows) for i in range(num_shards)]
 
     def copy(self) -> "SampleBatch":
-        out = SampleBatch({k: v.copy() for k, v in self._data.items()})
-        out.created_at = self.created_at
-        return out
+        return self._derived({k: v.copy() for k, v in self._data.items()})
 
     def size_bytes(self) -> int:
         return int(sum(v.nbytes for v in self._data.values()))
