@@ -109,7 +109,8 @@ class LMTokenPolicy:
     def logits_value(self, params: PyTree, obs: jax.Array):
         """No-cache forward: full-sequence attention, read at length-1.
 
-        Accepts any leading batch shape (the GAE bootstrap passes [T, N, D]).
+        Accepts any leading batch shape: acting passes [N, D], the GAE
+        bootstrap one time row [N, D] at a time, the learner [rows, D].
         """
         lead = obs.shape[:-1]
         tokens, length, _ = split_obs(obs.reshape(-1, obs.shape[-1]), self.ctx)
@@ -120,7 +121,9 @@ class LMTokenPolicy:
         return logits.reshape(lead + (self.vocab_size,)), value.reshape(lead)
 
     def value(self, params: PyTree, obs: jax.Array) -> jax.Array:
-        """Critic value only (GAE bootstrap at truncation boundaries)."""
+        """Critic value only: the GAE bootstrap's successor values, on one
+        [N, D] time row at a time (a fragment's last row and rows with a
+        truncation)."""
         return self.logits_value(params, obs)[1]
 
     def compute_actions(self, params: PyTree, obs: jax.Array, keys: jax.Array):

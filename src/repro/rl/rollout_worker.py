@@ -386,6 +386,7 @@ class VectorizedRolloutWorker(RolloutWorker):
         self.inference_client = inference_client
         self.max_inference_retries = max_inference_retries
         self.num_fragments_dropped = 0
+        self.num_bootstrap_rows = 0
         self.decode = decode
         super().__init__(
             env, policy, algo=algo, num_envs=num_envs, rollout_len=rollout_len, **kwargs
@@ -508,20 +509,55 @@ class VectorizedRolloutWorker(RolloutWorker):
         )
         return vstate, act_rng, lane_state, cols
 
-    def _postprocess_cols(self, params: PyTree, cols: Dict[str, jax.Array]):
-        """Advantage columns over assembled [T, B] rollout columns.
+    def _bootstrap_values(
+        self, params: PyTree, next_obs: jax.Array, truncateds: jax.Array
+    ) -> Tuple[jax.Array, jax.Array]:
+        """(v_next [T, B], rows evaluated): the critic's value of each
+        successor observation on the rows GAE reads, zeros elsewhere.
 
-        Shared verbatim by the vectorized and per-env paths (one jitted
-        function object), so the two engines are bit-comparable downstream
-        of acting.  Truncation bootstrap: the successor value (true
-        pre-reset next obs) is folded into the reward at truncated steps,
-        then the standard ``fused_gae`` runs with ``dones`` as the
+        GAE reads the successor value only at a truncated step (folded into
+        the reward) and on the last row (``last_value``); every other
+        successor's value is ``values[t+1]`` from acting.  So the last row is
+        always evaluated, and each earlier row only if one of its lanes
+        truncates, one ``[B]``-row ``lax.cond`` at a time: a single branch
+        over all earlier rows would reserve that whole batch's temporaries
+        even where it is not taken.
+        """
+        last = self.policy.value(params, next_obs[-1])
+
+        def row(args):
+            obs_t, trunc_t = args
+            return jax.lax.cond(
+                jnp.any(trunc_t != 0),
+                lambda o: self.policy.value(params, o),
+                lambda o: jnp.zeros_like(last),
+                obs_t,
+            )
+
+        early = jax.lax.map(row, (next_obs[:-1], truncateds[:-1]))
+        rows_read = 1 + jnp.sum(jnp.any(truncateds[:-1] != 0, axis=1), dtype=jnp.int32)
+        return jnp.concatenate([early, last[None]], axis=0), last.shape[0] * rows_read
+
+    def _postprocess_cols(self, params: PyTree, cols: Dict[str, jax.Array]):
+        """Advantage columns over assembled [T, B] rollout columns, plus the
+        scalar ``bootstrap_rows`` (successor rows the critic evaluated).
+
+        Shared verbatim by the vectorized, per-env and server paths (one
+        jitted function object), so the engines are bit-comparable
+        downstream of acting.  Truncation bootstrap: the successor value
+        (true pre-reset next obs) is folded into the reward at truncated
+        steps, then the standard ``fused_gae`` runs with ``dones`` as the
         accumulation mask — identical math to explicit next-value GAE, but
-        expressed through the existing kernel dispatch.
+        expressed through the existing kernel dispatch.  The critic runs
+        only on the last row and on rows with a truncation
+        (``_bootstrap_values``); rows it skips read 0, which the fold
+        multiplies by ``truncateds = 0`` anyway.
         """
         cols = dict(cols)
         if self.algo in ("pg", "ppo"):
-            v_next = self.policy.value(params, cols["next_obs"])
+            v_next, cols["bootstrap_rows"] = self._bootstrap_values(
+                params, cols["next_obs"], cols["truncateds"]
+            )
             rewards_adj = cols["rewards"] + self.gamma * v_next * cols["truncateds"]
             adv, ret = gae(
                 rewards_adj,
@@ -546,6 +582,7 @@ class VectorizedRolloutWorker(RolloutWorker):
             cols = dict(self._postprocess_jit(self.params, cols))
         with span("rollout.fetch"):
             self._record_completed(np.asarray(cols.pop("completed")), np.asarray(cols["dones"]))
+            self.num_bootstrap_rows += int(cols.pop("bootstrap_rows", 0))
             if self.algo in ("dqn", "sac"):
                 for k_ in ("logp", "values"):
                     cols.pop(k_, None)
@@ -659,6 +696,7 @@ class VectorizedRolloutWorker(RolloutWorker):
     def episode_stats(self) -> Dict[str, float]:
         stats = super().episode_stats()
         stats["fragments_dropped"] = float(self.num_fragments_dropped)
+        stats["bootstrap_rows"] = float(self.num_bootstrap_rows)
         return stats
 
 

@@ -38,6 +38,7 @@ from repro.rl import (
     StubEnv,
     VectorEnv,
 )
+from repro.rl.advantages import gae
 from repro.rl.rollout_worker import (
     EPS_STRIDE,
     MAX_LANES,
@@ -206,6 +207,73 @@ def test_truncation_bootstrap_matches_explicit_next_value_gae():
     np.testing.assert_allclose(
         np.asarray(out["returns"]), adv_ref + values, rtol=1e-4, atol=1e-3
     )
+
+
+def _truncations(case, T, B):
+    trunc = np.zeros((T, B), np.float32)
+    if case == "mid":
+        trunc[2, 0] = trunc[5, [1, 2]] = 1.0
+    elif case == "last":
+        trunc[T - 1, 1] = 1.0
+    elif case == "all":
+        trunc[:] = 1.0
+    return trunc
+
+
+@pytest.mark.parametrize("case", ["none", "mid", "last", "all"])
+def test_bootstrap_on_read_rows_matches_full_evaluation(case):
+    """Evaluating the critic only on the last row and on rows with a
+    truncation gives the advantages of evaluating it on all T x N
+    successors, and counts the rows it evaluated."""
+    w = make_vec_worker(
+        0, policy=ActorCriticPolicy(4, 2, loss_kind="ppo"), algo="ppo",
+        num_envs=3, rollout_len=8,
+    )
+    _, _, _, cols = w._vrollout_jit(w.params, w.vstate, w.act_rng, w.lane_state)
+    T, B = cols["rewards"].shape
+    trunc = _truncations(case, T, B)
+    cols = dict(cols, truncateds=jnp.asarray(trunc),
+                dones=jnp.maximum(cols["terminateds"], jnp.asarray(trunc)))
+    out = w._postprocess_jit(w.params, cols)
+
+    v_next = w.policy.value(w.params, cols["next_obs"])
+    adv_ref, ret_ref = gae(
+        cols["rewards"] + w.gamma * v_next * cols["truncateds"],
+        cols["values"], cols["dones"], v_next[-1], w.gamma, w.lam,
+    )
+    np.testing.assert_allclose(np.asarray(out["advantages"]), np.asarray(adv_ref),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(out["returns"]), np.asarray(ret_ref),
+                               rtol=1e-6, atol=1e-6)
+    rows_read = 1 + int(trunc[:-1].any(axis=1).sum())
+    assert int(out["bootstrap_rows"]) == B * rows_read
+
+
+def test_lm_bootstrap_evaluates_only_last_row():
+    """On the synchronous TokenEnv nothing truncates, so the LM critic's
+    full forward sees only each fragment's last [N] row."""
+    from repro.optim import adam
+    from repro.rl import LMTokenPolicy, TokenEnv
+
+    N, T = 4, 8
+    policy = LMTokenPolicy(ctx=16, vocab_size=16, d_model=16, n_layers=1)
+    w = VectorizedRolloutWorker(
+        TokenEnv(vocab_size=16, ctx=16, horizon=6), policy, algo="ppo",
+        num_envs=N, rollout_len=T, optimizer=adam(1e-3), seed=0, decode="cache",
+    )
+    shapes = []
+    orig = policy.value
+
+    def value(params, obs):
+        shapes.append(obs.shape)
+        return orig(params, obs)
+
+    policy.value = value
+    for _ in range(3):
+        w.sample()
+    assert shapes and set(shapes) == {(N, policy.obs_dim)}
+    stats = w.episode_stats()
+    assert stats["bootstrap_rows"] == 3 * N  # N per fragment of T * N rows
 
 
 # -------------------------------------------------------- decoupled inference
