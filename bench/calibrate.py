@@ -10,8 +10,9 @@ samplers' recorded fragments and three learner steps (no measured window),
 free the program, and print one JSON line of readings: the program against
 the float32 reference, and with ``--control`` the bfloat16 reference in the
 program's place.  ``--fault`` plants a fault of ``bench/faults.py`` first.
-The benchmark's own runs never run this.  A stopped program keeps some of
-its device memory, so at sizes that fill the chip give one seed per process.
+The benchmark's own runs never run this.  Each seed logs the device's bytes
+in use before the build, after set-up and once the program is freed; give
+one seed per process all the same, as the benchmark's runs start afresh.
 """
 
 import time
@@ -19,7 +20,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import gc  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -49,10 +49,12 @@ def main() -> int:
     for seed in [int(s) for s in args.seeds.split(",")]:
         t = time.perf_counter()
         algo, ws, rec, _ = harness.setup(cell, seed, fault)
+        rec.memory["after_setup"] = harness.bytes_in_use(cell.chips)
         harness.stop(algo, ws, rec)
         del algo, ws
-        gc.collect()
+        harness.free(cell.chips, rec.memory, f"seed {seed}")
         line = {"workload": cell.name, "seed": seed, "fault": args.fault or None,
+                "memory_bytes_in_use": rec.memory,
                 "program": harness.readings(cell, seed, rec, detail=True)}
         if args.control:
             line["control"] = harness.readings(cell, seed, rec, control=True)

@@ -13,7 +13,8 @@ with whole ``Algorithm.train()`` iterations, measure ``train()`` back to back
 for the window (whole cycles of the plan, at least ``--seconds``), free the
 program, then compare what set-up recorded of the timed path (each sampler's
 fragments through its own ``sample()``, the first three learner steps) with
-the reference.
+the reference, and count the rows the learner's steps took against the plan's
+passes over what the program counts as trained.
 """
 
 from __future__ import annotations
@@ -77,10 +78,12 @@ def reader(metric: str):
 
 
 class Cell:
-    """A workload of ``BENCHMARK.json`` with its files resolved."""
+    """A workload of ``BENCHMARK.json`` with its files resolved (a test may
+    pass any of them, the plan module too)."""
 
     def __init__(self, bench: dict, name: str, model: Optional[dict] = None,
-                 traffic: Optional[dict] = None, limits: Optional[dict] = None):
+                 traffic: Optional[dict] = None, limits: Optional[dict] = None,
+                 plan: Any = None):
         cells = {w["name"]: w for w in bench["workloads"]}
         if name not in cells:
             raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
@@ -89,7 +92,7 @@ class Cell:
         self.model = model or load_json(ROOT / cfg["file"])
         self.traffic = traffic or load_json(BENCH / "traffic" / f"{self.spec['traffic']}.json")
         self.limits = limits if limits is not None else load_json(BENCH / "limits" / f"{name}.json")
-        self.plan = importlib.import_module(f"bench.plans.{self.traffic['plan']}")
+        self.plan = plan or importlib.import_module(f"bench.plans.{self.traffic['plan']}")
         self.chips = int(self.spec["chips"])
 
     def metrics(self, trace: bool) -> List[dict]:
@@ -151,14 +154,20 @@ def _diff_norms(new, old_host) -> Dict[str, float]:
 class Recorder:
     """Records, through the window's own calls, what the comparison needs:
     the fragments each sampler returns from ``sample()`` while ``collect``
-    holds (set-up, before any learner step), and the first three learner
-    steps (batch, loss, the gradient as Adam's first moment holds it after
-    step one, and the parameters' change after step three).  Unhooks the
-    learner once done; the samplers' hook only copies rows when asked to."""
+    holds (set-up, before any learner step), the first three learner steps
+    (batch, loss, the gradient as Adam's first moment holds it after step
+    one, and the parameters' change after step three), and the rows that
+    every compiled learner step takes, set-up and window alike.  Unhooks
+    ``learn_on_batch`` once done; the samplers' hook only copies rows when
+    asked to.  ``memory`` holds the device's bytes in use before the build
+    and at the points a run adds."""
 
-    def __init__(self, ws, plan, adam_b1: float):
+    def __init__(self, ws, plan, adam_b1: float, memory: Optional[dict] = None):
         self.plan, self.ws, self.adam_b1 = plan, ws, adam_b1
+        self.memory = memory if memory is not None else {}
         self.lw = ws.local_worker()
+        self.learned_rows = 0  # rows passed to the compiled learner step
+        self.trained = 0       # the program's num_steps_trained at the last train()
         self.fragments: Dict[int, List[dict]] = {}
         self.collect = False
         self.steps: List[dict] = []
@@ -168,6 +177,8 @@ class Recorder:
         self._p0 = None
         self._orig_learn = self.lw.learn_on_batch
         self.lw.learn_on_batch = self._learn
+        self._step = self.lw._learn_jit
+        self.lw._learn_jit = self._count_rows
         for actor in ws.remote_workers().actors:
             target = actor.target
             target.sample = self._sampler(target, target.sample)
@@ -175,11 +186,12 @@ class Recorder:
     def release(self) -> None:
         """Drop every hook and reference into the program, so that stopping
         it frees its device state before the reference runs."""
-        if self.lw is not None and "learn_on_batch" in vars(self.lw):
-            del self.lw.learn_on_batch
+        if self.lw is not None:
+            for name in ("learn_on_batch", "_learn_jit"):
+                vars(self.lw).pop(name, None)
         for actor in self.ws.remote_workers().actors:
             vars(actor.target).pop("sample", None)
-        self.lw = self.ws = self._p0 = None
+        self.lw = self.ws = self._p0 = self._orig_learn = self._step = None
 
     @property
     def done(self) -> bool:
@@ -208,6 +220,10 @@ class Recorder:
                     fut.result()
         finally:
             self.collect = False
+
+    def _count_rows(self, params, target_params, opt_state, batch, key):
+        self.learned_rows += int(next(iter(batch.values())).shape[0])
+        return self._step(params, target_params, opt_state, batch, key)
 
     def _learn(self, batch, *a, **k):
         import jax
@@ -325,10 +341,23 @@ def compare(cand: dict, want: dict, detail: bool = False) -> Dict[str, float]:
     return {k: (v if math.isfinite(v) else MISSING) for k, v in r.items()}
 
 
+def rows_gap(cell: Cell, rec: Recorder) -> Dict[str, float]:
+    """``learner.rows_gap``: how far the rows that the compiled learner steps
+    took fall from the plan's passes (``learner_passes``: PPO's epochs) over
+    the rows the program counts as trained, as a share of the latter; none
+    where the plan fixes no number of passes per ``train()``."""
+    passes = cell.plan.learner_passes(cell.traffic)
+    if passes is None:
+        return {}
+    want = passes * rec.trained
+    return {"learner.rows_gap": abs(rec.learned_rows - want) / want if want else MISSING}
+
+
 def readings(cell: Cell, seed: int, rec: Recorder, control: bool = False,
              detail: bool = False) -> Dict[str, float]:
-    """Program against the float32 reference; with ``control``, the
-    bfloat16 reference put in the program's place."""
+    """Program against the float32 reference, and its learner's rows against
+    the plan's passes; with ``control``, the bfloat16 reference put in the
+    program's place."""
     import jax
     import jax.numpy as jnp
 
@@ -340,9 +369,8 @@ def readings(cell: Cell, seed: int, rec: Recorder, control: bool = False,
         cand = reference_outputs(
             cell.plan.Reference(cell.model, cell.traffic, pseed, dtype=jnp.bfloat16), rec,
             cell.model, cell.traffic)
-    else:
-        cand = program_outputs(rec)
-    return compare(cand, want, detail)
+        return compare(cand, want, detail)
+    return dict(compare(program_outputs(rec), want, detail), **rows_gap(cell, rec))
 
 
 # ---------------------------------------------------------------------- run
@@ -378,6 +406,15 @@ def peak_for(kind: str) -> dict:
     return peaks[kind]
 
 
+def bytes_in_use(chips: int) -> Optional[int]:
+    """Device bytes in use on the fullest chip, where the backend reports it."""
+    import jax
+
+    used = [st["bytes_in_use"] for st in (d.memory_stats() for d in jax.devices()[:chips])
+            if st and "bytes_in_use" in st]
+    return max(used) if used else None
+
+
 def memory_peak(chips: int) -> int:
     import jax
 
@@ -390,19 +427,38 @@ def memory_peak(chips: int) -> int:
 
 
 def stop(algo, ws, rec: Optional[Recorder] = None) -> None:
+    from repro.core.metrics import set_metrics_for_thread
+
     try:
         algo.stop()
     finally:
         ws.stop()
+        # The driving thread's metrics context names the last actor gathered.
+        set_metrics_for_thread(None)
         if rec is not None:
             rec.release()
+
+
+def free(chips: int, memory: dict, what: str = "memory") -> None:
+    """After ``stop`` and once the caller has dropped the program: collect
+    what only cycles still hold (each worker and its jitted bound methods),
+    and JAX's in-memory caches of traced and compiled programs.  Adds the
+    device bytes still in use to ``memory`` (the recorder's) and logs it."""
+    import jax
+
+    gc.collect()
+    jax.clear_caches()
+    gc.collect()
+    memory["after_free"] = bytes_in_use(chips)
+    log(f"{what}: bytes in use " + ", ".join(f"{k} {v}" for k, v in memory.items()))
 
 
 def setup(cell: Cell, seed: int, fault: Optional[Callable] = None):
     """Build the cell's system, hook the recorder, plant ``fault`` (tests
     and the control runs only), and warm up.  Returns (algo, ws, rec, res)."""
+    memory = {"before_build": bytes_in_use(cell.chips)}
     algo, ws = cell.plan.build(cell.model, cell.traffic, program_seed(seed))
-    rec = Recorder(ws, cell.plan, cell.model["optimizer"]["b1"])
+    rec = Recorder(ws, cell.plan, cell.model["optimizer"]["b1"], memory)
     if fault is not None:
         fault(ws, cell)
     res = None
@@ -416,6 +472,7 @@ def setup(cell: Cell, seed: int, fault: Optional[Callable] = None):
     except BaseException:
         stop(algo, ws, rec)
         raise
+    rec.trained = trained(res)
     return algo, ws, rec, res
 
 
@@ -484,13 +541,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         setup_s = time.perf_counter() - t_start
         w = window(algo, ws, seconds, trace_dir, cell.plan.cycle_iterations(cell.traffic))
         compiles_window = clock.lap()
-        units = trained(w["res"]) - before
+        rec.trained = trained(w["res"])
+        units = rec.trained - before
         mem_peak = memory_peak(cell.chips)
+        rec.memory["after_window"] = bytes_in_use(cell.chips)
     finally:
         stop(algo, ws, rec)
     rows, rec.rows = rec.rows, None
     del algo, ws, res, w["res"]
-    gc.collect()
+    free(cell.chips, rec.memory)
 
     window_s = w["t1"] - w["t0"]
     facts = {
@@ -535,6 +594,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         result["breakdown"] = breakdown
     result["compiles"] = {"setup": compiles_setup[0], "setup_s": compiles_setup[1],
                           "window": compiles_window[0], "window_s": compiles_window[1]}
+    result["memory_bytes_in_use"] = rec.memory
     result["readings"] = checks
     result["checks"] = compared
     return result

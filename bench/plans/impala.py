@@ -19,6 +19,31 @@ from bench.reference import mlp as ref_mlp
 from bench.reference import rl as ref_rl
 
 
+# V-trace runs in the learner; the postprocess bootstraps nothing.
+BOOTSTRAPS_WITH_CRITIC = False
+
+# CPU test sizes (``tiny``): the configuration's own widths, fewer lanes.
+TINY_TRAFFIC = dict(sampling_workers=2, num_envs=16, rollout_len=8, train_batch=128,
+                    warmup_iters=3)
+
+
+def tiny(m: dict, t: dict):
+    """(configuration, traffic) cut to sizes a CPU test run can hold."""
+    return m, dict(t, **TINY_TRAFFIC)
+
+
+def batch_unit(t: dict) -> int:
+    """Rows that a cut of the learner's batch keeps together: V-trace reads
+    whole length-``rollout_len`` traces."""
+    return t["rollout_len"]
+
+
+def learner_passes(t: dict) -> None:
+    """The learner thread steps on whatever batches have arrived, so no
+    number of learner rows belongs to one ``train()``."""
+    return None
+
+
 def _worker(m: dict, t: dict, seed: int, index: int):
     from repro.optim import adam
     from repro.rl import ActorCriticPolicy, VectorizedRolloutWorker

@@ -6,6 +6,14 @@ sizes) and a traffic mix (prompt lengths, horizon, lanes, fragment length,
 learner schedule).  ``Reference`` is the plain float32 model of the same
 policy and losses (``bench/reference``), built from the seed alone.
 
+A plan for another policy architecture is a short module of its own: it
+supplies ``make_policy(m, t)`` and a ``build`` that passes it to this one's,
+subclasses ``Reference`` with its own ``net`` (a plain reference module),
+gives its own CPU test sizes (``tiny``), and takes ``check_fragments``,
+``cycle_iterations``, ``row_stats``, ``covers``, ``learner_passes`` and the
+fault hooks (``BOOTSTRAPS_WITH_CRITIC``, ``batch_unit``) from here (PERF.md,
+section 3).
+
 Every lane of a sampler starts and ends its episodes together (``sync``), so
 ``horizon / rollout_len`` fragments make one whole episode: set-up records
 that many of each sampler for the comparison, so that every sequence length
@@ -24,21 +32,60 @@ from bench.reference import lm as ref_lm
 from bench.reference import rl as ref_rl
 
 
-def _worker(m: dict, t: dict, seed: int, index: int):
+# The postprocess bootstraps GAE with the critic's values: ``altered_bootstrap``
+# applies.
+BOOTSTRAPS_WITH_CRITIC = True
+
+# CPU test sizes (``tiny``).  d_model 512 keeps the logits as spread as at the
+# cell's width (a token altered where it is produced must change its
+# log-probability visibly).
+TINY_MODEL = dict(num_layers=1, d_model=512, num_heads=8, num_kv_heads=8, head_dim=64,
+                  d_ff=2048, vocab_size=64)
+TINY_TRAFFIC = dict(ctx=32, min_prompt=4, max_prompt=8, horizon=16, num_envs=4,
+                    rollout_len=8, train_batch=32, minibatch=8, warmup_iters=1)
+
+
+def tiny(m: dict, t: dict):
+    """(configuration, traffic) cut to sizes a CPU test run can hold."""
+    return dict(m, **TINY_MODEL), dict(t, **TINY_TRAFFIC)
+
+
+def batch_unit(t: dict) -> int:
+    """Rows that a cut of the learner's batch keeps together: PPO's loss
+    reads each row alone."""
+    return 1
+
+
+def learner_passes(t: dict) -> int:
+    """Times the learner steps over each row that one ``train()`` trains:
+    PPO's epochs (``learner.rows_gap``, the ``one_epoch`` fault)."""
+    return int(t["sgd_epochs"])
+
+
+def make_policy(m: dict, t: dict):
+    """``LMTokenPolicy`` at the configuration's sizes, once ``check_config``
+    has passed."""
+    from repro.rl import LMTokenPolicy
+
+    check_config(m)
+    c = m["loss"]
+    return LMTokenPolicy(
+        ctx=t["ctx"], vocab_size=m["vocab_size"], d_model=m["d_model"],
+        n_layers=m["num_layers"], num_heads=m["num_heads"], num_kv_heads=m["num_kv_heads"],
+        clip_eps=c["clip_eps"], vf_coef=c["vf_coef"], ent_coef=c["ent_coef"],
+    )
+
+
+def _worker(m: dict, t: dict, seed: int, make_policy, index: int):
     from repro.optim import adam
-    from repro.rl import LMTokenPolicy, TokenEnv, VectorizedRolloutWorker
+    from repro.rl import TokenEnv, VectorizedRolloutWorker
 
     env = TokenEnv(
         vocab_size=m["vocab_size"], ctx=t["ctx"], min_prompt=t["min_prompt"],
         max_prompt=t["max_prompt"], horizon=t["horizon"], sync=t["sync"],
     )
-    c = m["loss"]
-    policy = LMTokenPolicy(
-        ctx=t["ctx"], vocab_size=m["vocab_size"], d_model=m["d_model"],
-        n_layers=m["num_layers"], num_heads=m["num_heads"], num_kv_heads=m["num_kv_heads"],
-        clip_eps=c["clip_eps"], vf_coef=c["vf_coef"], ent_coef=c["ent_coef"],
-    )
-    o = m["optimizer"]
+    policy = make_policy(m, t)
+    c, o = m["loss"], m["optimizer"]
     return VectorizedRolloutWorker(
         env, policy, algo="ppo", num_envs=t["num_envs"], rollout_len=t["rollout_len"],
         gamma=c["gamma"], lam=c["lam"], seed=seed, worker_index=index, decode="cache",
@@ -57,13 +104,14 @@ def check_config(m: dict) -> None:
         raise SystemExit(f"configuration differs from what LMTokenPolicy builds: {bad}")
 
 
-def build(m: dict, t: dict, seed: int):
-    """(algo, workers) of the cell."""
+def build(m: dict, t: dict, seed: int, make_policy=make_policy):
+    """(algo, workers) of the cell; ``make_policy(m, t)`` builds each
+    worker's policy (a plan of another architecture passes its own)."""
     from repro import flow
     from repro.core.workers import WorkerSet
 
-    check_config(m)
-    ws = WorkerSet.create(functools.partial(_worker, m, t, seed), t["sampling_workers"])
+    ws = WorkerSet.create(functools.partial(_worker, m, t, seed, make_policy),
+                          t["sampling_workers"])
     algo = flow.Algorithm.from_plan(
         "ppo_lm", ws, train_batch_size=t["train_batch"], num_sgd_iter=t["sgd_epochs"],
         sgd_minibatch_size=t["minibatch"], num_learners=t["num_learners"], decode="cache",
@@ -122,23 +170,27 @@ class Reference:
     """The plain model and losses, in float32 at full matmul precision, or
     in bfloat16 (the control)."""
 
+    # The plain model: init_params(m, key), hidden(m, p, tokens), heads(p, h)
+    # and logits_value(m, p, obs).  A plan of another architecture subclasses
+    # this class with its own.
+    net = ref_lm
     seqs_per_block = 8    # sequences per forward
     rows_per_block = 512  # rows per read of the heads
 
     def __init__(self, m: dict, t: dict, seed: int, dtype=jnp.float32):
         self.m, self.t, self.seed, self.dtype = m, t, seed, dtype
-        self._hidden = jax.jit(lambda p, tok: ref_lm.hidden(m, self.cast(p), tok))
-        self._heads = jax.jit(lambda p, h, idx: ref_lm.heads(self.cast(p), h[idx]))
+        self._hidden = jax.jit(lambda p, tok: self.net.hidden(m, self.cast(p), tok))
+        self._heads = jax.jit(lambda p, h, idx: self.net.heads(self.cast(p), h[idx]))
         self._vg = jax.jit(jax.value_and_grad(self._loss))
 
     def cast(self, p):
         return jax.tree_util.tree_map(lambda x: x.astype(self.dtype), p)
 
     def init(self, worker: int):
-        return ref_lm.init_params(self.m, ref_rl.worker_key(self.seed, worker))
+        return self.net.init_params(self.m, ref_rl.worker_key(self.seed, worker))
 
     def _loss(self, p, batch):
-        logits, values = ref_lm.logits_value(self.m, self.cast(p), batch["obs"])
+        logits, values = self.net.logits_value(self.m, self.cast(p), batch["obs"])
         return ref_rl.ppo_loss(logits, values, batch, self.m["loss"])
 
     def logits_value(self, p, obs):
